@@ -134,8 +134,10 @@ _COLLECTIVES = (
     "collective-permute",
 )
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+# result shape (a tuple, or dtype[dims] with a layout such as the TPU's
+# {0:T(1024)}), then the collective's opcode
 _OP_RE = re.compile(
-    r"=\s*(?:\([^)]*\)|[\w\[\],{}\s]+?)\s*"
+    r"=\s*(?:\(.*?\)|\w+\[[\d,]*\](?:\{[^}]*\})?)\s*"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(?:-start|-done)?\("
 )

@@ -36,7 +36,11 @@ class SyntheticLM:
 
 
 class Prefetcher:
-    """Background-thread prefetch + device transfer (straggler hiding)."""
+    """Background-thread prefetch + device transfer (straggler hiding).
+
+    Each batch is transferred to the device once.  An exception raised in
+    the worker (a failed transfer, say) is raised again by ``next()``.
+    """
 
     def __init__(self, dataset: SyntheticLM, mesh: Mesh, start_step: int = 0,
                  depth: int = 2, extras: dict | None = None):
@@ -45,6 +49,7 @@ class Prefetcher:
         self.extras = extras or {}
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._step = start_step
+        self._error: BaseException | None = None
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
@@ -58,21 +63,35 @@ class Prefetcher:
             out[k] = jax.device_put(v, sh)
         return out
 
-    def _worker(self):
-        step = self._step
+    def _put(self, item) -> bool:
+        """Enqueue ``item`` unless closed first; True once it is queued."""
         while not self._stop.is_set():
             try:
-                self._q.put((step, self._shard(self.dataset.batch_at(step))),
-                            timeout=0.5)
-                step += 1
+                self._q.put(item, timeout=0.5)
+                return True
             except queue.Full:
                 continue
+        return False
+
+    def _worker(self):
+        step = self._step
+        try:
+            while self._put((step, self._shard(self.dataset.batch_at(step)))):
+                step += 1
+        except Exception as e:  # handed to the consumer, raised by next()
+            self._put(e)
 
     def __iter__(self):
         return self
 
     def __next__(self):
-        return self._q.get()
+        if self._error is None:
+            item = self._q.get()
+            if not isinstance(item, Exception):
+                return item
+            self._error = item
+        raise self._error
 
     def close(self):
         self._stop.set()
+        self._thread.join(timeout=10)

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute in ``interpret=True`` mode — the
-kernel body runs through the Pallas interpreter for correctness validation.
-On TPU they lower to Mosaic.  ``flash_attention`` installs a
+Kernels lower to Mosaic for the TPU.  ``interpret=True`` runs the kernel
+body through the Pallas interpreter instead (any backend, for correctness
+checks); only the caller decides that.  ``flash_attention`` installs a
 ``jax.custom_vjp`` whose backward recomputes attention blockwise in XLA
 (the standard recompute-based flash backward data-flow), so the kernel can
 be used in training code.
@@ -20,26 +20,23 @@ from repro.kernels import rmsnorm as _rn
 from repro.models.common import flash_attention_xla
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal=True, window=0, q_offset=0):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, causal=True, window=0, q_offset=0,
+                    interpret=False):
     if q_offset:
         # Decode-style offsets take the XLA path (kernel assumes offset 0).
         return flash_attention_xla(q, k, v, causal=causal, window=window,
                                    q_offset=q_offset)
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=_interpret())
+                               interpret=interpret)
 
 
-def _fa_fwd(q, k, v, causal, window, q_offset):
-    out = flash_attention(q, k, v, causal, window, q_offset)
+def _fa_fwd(q, k, v, causal, window, q_offset, interpret):
+    out = flash_attention(q, k, v, causal, window, q_offset, interpret)
     return out, (q, k, v)
 
 
-def _fa_bwd(causal, window, q_offset, res, g):
+def _fa_bwd(causal, window, q_offset, interpret, res, g):
     q, k, v = res
 
     def f(q, k, v):
@@ -53,9 +50,9 @@ def _fa_bwd(causal, window, q_offset, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-def rglru_scan(a, b, h0=None):
-    return _rg.rglru_scan(a, b, h0, interpret=_interpret())
+def rglru_scan(a, b, h0=None, interpret=False):
+    return _rg.rglru_scan(a, b, h0, interpret=interpret)
 
 
-def rmsnorm(x, w, eps=1e-6):
-    return _rn.rmsnorm(x, w, eps, interpret=_interpret())
+def rmsnorm(x, w, eps=1e-6, interpret=False):
+    return _rn.rmsnorm(x, w, eps, interpret=interpret)

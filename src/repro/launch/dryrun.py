@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the production mesh (16x16 single-pod or 2x16x16
@@ -22,6 +19,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -134,9 +132,7 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                                 max(shape.global_batch, world), shape.kind)
             jit_step, init_state, orders = make_themis_train_step(
                 api, mesh, parallel, _tcfg())
-            params_s = api.param_spec()
-            opt_s = jax.eval_shape(lambda: _themis_opt_spec(
-                api, mesh, parallel))
+            params_s, opt_s = jax.eval_shape(init_state)
             batch_s = api.batch_spec(shape)
             lowered = jit_step.lower(params_s, opt_s, batch_s)
     elif shape.kind == "prefill":
@@ -218,23 +214,6 @@ def _tcfg():
     return TrainConfig()
 
 
-def _themis_opt_spec(api, mesh, parallel):
-    # shape-only stand-in for the manual-mode optimizer state
-    import jax.numpy as jnp
-    import math
-    from repro.models.registry import count_params
-
-    axes = {a: s for a, s in mesh.shape.items() if s > 1}
-    world = math.prod(axes.values())
-    n = count_params(api.param_spec())
-    n_chunks = parallel.chunks_per_collective
-    per = -(-n // (n_chunks * world)) * world
-    z = jnp.zeros((n_chunks, per), jnp.float32)
-    return {"master": z, "m": z, "v": z,
-            "count": jnp.zeros((), jnp.int32),
-            "err": jnp.zeros((), jnp.float32)}
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
@@ -250,6 +229,9 @@ def main():
     ap.add_argument("--out", default="runs/dryrun")
     args = ap.parse_args()
 
+    # placeholder host devices for the production meshes; must precede the
+    # first use of a jax backend in this process
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     os.makedirs(args.out, exist_ok=True)
     cells = []
     if args.all:

@@ -28,10 +28,12 @@ def main():
     import numpy as np
 
     from repro.configs import ParallelConfig, ShapeConfig, get_arch
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.models import build_model
     from repro.train.serve import make_serve_fns
 
+    enable_compile_cache()
     data, model = (int(x) for x in args.mesh.split("x"))
     mesh = make_mesh((data, model), ("data", "model"))
     cfg = get_arch(args.arch, reduced=args.reduced)

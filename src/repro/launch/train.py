@@ -4,6 +4,10 @@
         --steps 200 --batch 8 --seq 256 --mesh 1x1 --reduced \
         --dp-sync gspmd --ckpt-dir runs/ckpt
 
+``--layers N`` cuts the depth of the chosen architecture and keeps its
+widths (a full-width model on one chip).  Compiled programs go to the
+persistent cache of ``repro.launch.cache``.
+
 Features: synthetic data pipeline with host prefetch, AdamW + cosine LR,
 grad clipping, gradient accumulation, periodic atomic checkpoints with
 async writer, resume-from-latest (exact data-cursor resume), Themis or
@@ -17,10 +21,13 @@ import argparse
 import time
 
 
-def main():
+def main(argv: list[str] | None = None) -> list[float]:
+    """Train; ``argv`` defaults to ``sys.argv[1:]``.  Returns the losses."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="override num_layers (depth only; widths stay)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
@@ -33,7 +40,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--compression", default="none", choices=["none", "int8"])
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     import jax
     import numpy as np
@@ -41,6 +48,7 @@ def main():
     from repro.ckpt import AsyncCheckpointer, latest_step, restore
     from repro.configs import ParallelConfig, TrainConfig, get_arch
     from repro.data import Prefetcher, SyntheticLM
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.models import build_model
     from repro.train.step import (
@@ -55,9 +63,12 @@ def main():
     data, model, pods = dims
     names = ("pod", "data", "model") if pods > 1 else ("data", "model")
     shape = (pods, data, model) if pods > 1 else (data, model)
+    enable_compile_cache()
     mesh = make_mesh(shape, names)
 
     cfg = get_arch(args.arch, reduced=args.reduced)
+    if args.layers:
+        cfg = cfg.replace(num_layers=args.layers)
     api = build_model(cfg)
     parallel = ParallelConfig(data=data, model=model, pods=pods,
                               dp_sync=args.dp_sync,
@@ -78,7 +89,7 @@ def main():
         params, opt = init_state()
         uniq = sorted(set(orders))
         print(f"[train] themis chunk orders ({len(orders)} chunks): "
-              + ", ".join("->".join(o) for o in uniq))
+              + ", ".join("->".join(o) or "local" for o in uniq))
 
     start_step = 0
     ckpt = None
@@ -102,6 +113,9 @@ def main():
             break
         params, opt, metrics = jit_step(params, opt, batch)
         losses.append(float(metrics["loss"]))
+        if len(losses) == 1:  # the steady-state window starts after compile
+            jax.block_until_ready((params, opt))
+            t_steady = time.perf_counter()
         if (step + 1) % args.log_every == 0:
             dt = (time.time() - t_last) / args.log_every
             t_last = time.time()
@@ -111,9 +125,19 @@ def main():
         if ckpt and (step + 1) % tcfg.checkpoint_every == 0:
             ckpt.save_async(step + 1, (params, opt),
                             extra={"next_step": step + 1, "seed": tcfg.seed})
+    jax.block_until_ready((params, opt))
+    if len(losses) > 1:
+        dt = (time.perf_counter() - t_steady) / (len(losses) - 1)
+        print(f"[train] steady-state step time {dt * 1e3} ms "
+              f"(mean of {len(losses) - 1} steps after the first)")
     pf.close()
     if ckpt:
         ckpt.wait()
+    stats = jax.local_devices()[0].memory_stats()
+    if stats and "peak_bytes_in_use" in stats:
+        # the device keeps one peak for the whole process, not one per call
+        print(f"[train] peak_bytes_in_use {stats['peak_bytes_in_use']} "
+              f"on {jax.local_devices()[0]} (since the process started)")
     print(f"[train] done: {len(losses)} steps, "
           f"loss {losses[0]:.4f} -> {np.mean(losses[-10:]):.4f}")
     return losses

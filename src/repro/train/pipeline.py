@@ -17,7 +17,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.launch.compat import axis_size_compat, shard_map_compat
 from repro.models import transformer as tr
 from repro.models.common import cross_entropy, rms_norm
 
@@ -33,7 +32,7 @@ def pipeline_forward(params, tokens, cfg: ModelConfig, *, n_micro: int,
     return zeros — the loss is psum'd over the axis).
     """
     stage = jax.lax.axis_index(axis)
-    n_stage = axis_size_compat(axis)
+    n_stage = jax.lax.axis_size(axis)
     dt = jnp.dtype(cfg.dtype)
     b, s = tokens.shape
     assert b % n_micro == 0
@@ -82,7 +81,7 @@ def make_pipeline_loss(cfg: ModelConfig, mesh: Mesh, n_micro: int,
     """(stage_params, tokens, labels) -> scalar loss; shard_map'd."""
 
     def loss_shard(params, tokens, labels):
-        n_stage = axis_size_compat(axis)
+        n_stage = jax.lax.axis_size(axis)
         stage = jax.lax.axis_index(axis)
         logits = pipeline_forward(params, tokens, cfg, n_micro=n_micro,
                                   axis=axis)
@@ -91,12 +90,12 @@ def make_pipeline_loss(cfg: ModelConfig, mesh: Mesh, n_micro: int,
         l = jnp.where(stage == n_stage - 1, l, 0.0)
         return jax.lax.psum(l, axis)
 
-    return shard_map_compat(
+    return jax.shard_map(
         loss_shard, mesh=mesh,
         in_specs=({"embed": P(), "blocks": P(axis), "ln_f": P(),
                    "lm_head": P()}, P(), P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
 
 

@@ -26,7 +26,6 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.compat import axis_size_compat, shard_map_compat
 from repro.comms.hierarchical import (
     _quantize,
     chunked_all_gather,
@@ -44,10 +43,8 @@ from repro.train.optimizer import adamw_init, adamw_update, clip_by_global_norm,
 # --------------------------------------------------------------------------
 # GSPMD mode
 # --------------------------------------------------------------------------
-def make_gspmd_train_step(
-    api: ModelApi, mesh: Mesh, parallel: ParallelConfig, tcfg: TrainConfig
-):
-    """Returns (jit_step, param_shardings, opt_shardings, batch_sharding_fn)."""
+def _gspmd_shardings(api: ModelApi, mesh: Mesh, parallel: ParallelConfig):
+    """(param shardings, AdamW state shardings) of the GSPMD mode."""
     pspec_tree = api.param_spec()
     p_shard = param_shardings(pspec_tree, mesh, parallel)
 
@@ -57,7 +54,14 @@ def make_gspmd_train_step(
         )
 
     mv = jax.tree.map(opt_shard_of, pspec_tree, p_shard)
-    o_shard = {"m": mv, "v": mv, "count": NamedSharding(mesh, P())}
+    return p_shard, {"m": mv, "v": mv, "count": NamedSharding(mesh, P())}
+
+
+def make_gspmd_train_step(
+    api: ModelApi, mesh: Mesh, parallel: ParallelConfig, tcfg: TrainConfig
+):
+    """Returns (jit_step, param_shardings, opt_shardings, batch_sharding_fn)."""
+    p_shard, o_shard = _gspmd_shardings(api, mesh, parallel)
 
     n_micro = max(tcfg.microbatch, 1)
 
@@ -113,11 +117,12 @@ def make_gspmd_train_step(
 def gspmd_init_state(api: ModelApi, mesh: Mesh, parallel: ParallelConfig,
                      seed: int = 0):
     """Initialize params + optimizer state directly into sharded buffers."""
-    pspec_tree = api.param_spec()
-    p_shard = param_shardings(pspec_tree, mesh, parallel)
-    params = jax.jit(api.init, out_shardings=p_shard)(jax.random.key(seed))
-    opt = adamw_init(params)
-    return params, opt
+    def init(key):
+        params = api.init(key)
+        return params, adamw_init(params)
+
+    return jax.jit(init, out_shardings=_gspmd_shardings(api, mesh, parallel))(
+        jax.random.key(seed))
 
 
 # --------------------------------------------------------------------------
@@ -127,7 +132,7 @@ def _local_shard(y: jax.Array, order: tuple[str, ...]) -> jax.Array:
     """This device's nested block of a replicated chunk (zero-comm slicing
     matching the psum_scatter ownership for the given axis order)."""
     for ax in order:
-        a = axis_size_compat(ax)
+        a = jax.lax.axis_size(ax)
         i = jax.lax.axis_index(ax)
         ln = y.shape[0] // a
         y = jax.lax.dynamic_slice(y, (i * ln,), (ln,))
@@ -158,7 +163,9 @@ def make_themis_train_step(
     pad_total = n_chunks * per_chunk - n_params
     use_int8 = parallel.compression == "int8"
 
-    dp_axes = axes if len(axes) > 1 else axes[0]
+    # one mesh axis -> its name; several -> the tuple; none (a single
+    # device) -> None: replicated specs, no collectives, world = 1
+    dp_axes = axes[0] if len(axes) == 1 else (axes or None)
     shard_spec = P(None, dp_axes)  # (C, per_chunk) scattered layout
 
     def step_shard(params, master, m, v, count, err, batch):
@@ -206,13 +213,13 @@ def make_themis_train_step(
                 {"loss": loss, "gnorm": gnorm, "lr": lr})
 
     err_spec = P(dp_axes, None) if use_int8 else P()
-    shard_step = shard_map_compat(
+    shard_step = jax.shard_map(
         step_shard,
         mesh=mesh,
         in_specs=(P(), shard_spec, shard_spec, shard_spec, P(), err_spec,
                   P(dp_axes)),
         out_specs=(P(), shard_spec, shard_spec, shard_spec, P(), err_spec, P()),
-        check=False,
+        check_vma=False,
     )
 
     def step(params, opt_state, batch):
@@ -223,32 +230,37 @@ def make_themis_train_step(
         return new_p, {"master": master2, "m": m2, "v": v2, "count": c2,
                        "err": err2}, metrics
 
-    def init_state(seed: int = 0):
-        params = api.init(jax.random.key(seed))
-        flat, _ = ravel_pytree(params)
+    def build_master(pf):
+        chunks = jnp.pad(pf.astype(jnp.float32), (0, pad_total)).reshape(
+            n_chunks, per_chunk)
+        return jnp.stack([_local_shard(chunks[i], orders[i])
+                          for i in range(n_chunks)])
 
-        def build_master(pf):
-            chunks = jnp.pad(pf.astype(jnp.float32), (0, pad_total)).reshape(
-                n_chunks, per_chunk)
-            return jnp.stack([_local_shard(chunks[i], orders[i])
-                              for i in range(n_chunks)])
-
-        master = jax.jit(
-            shard_map_compat(build_master, mesh=mesh, in_specs=P(),
-                          out_specs=shard_spec, check=False)
-        )(flat)
-        zeros = jnp.zeros_like(master)
-        if use_int8:
-            err = jax.device_put(
-                jnp.zeros((world, n_params), jnp.float32),
-                NamedSharding(mesh, P(dp_axes, None)))
-        else:
-            err = jnp.zeros((), jnp.float32)
-        opt = {"master": master, "m": zeros, "v": jnp.copy(zeros),
-               "count": jnp.zeros((), jnp.int32), "err": err}
+    def init(key):
+        params = api.init(key)
+        master = jax.shard_map(build_master, mesh=mesh, in_specs=P(),
+                               out_specs=shard_spec,
+                               check_vma=False)(ravel_pytree(params)[0])
+        err_shape = (world, n_params) if use_int8 else ()
+        opt = {"master": master, "m": jnp.zeros_like(master),
+               "v": jnp.zeros_like(master),
+               "count": jnp.zeros((), jnp.int32),
+               "err": jnp.zeros(err_shape, jnp.float32)}
         return params, opt
 
-    jit_step = jax.jit(step, donate_argnums=(1,))
+    # One program that lays every buffer out on the mesh: params replicated,
+    # optimizer state scattered.  The raveled copy of the params is a
+    # temporary of this program and is freed when it returns.
+    rep = NamedSharding(mesh, P())
+    scat = NamedSharding(mesh, shard_spec)
+    jit_init = jax.jit(init, out_shardings=(
+        rep, {"master": scat, "m": scat, "v": scat, "count": rep,
+              "err": NamedSharding(mesh, err_spec)}))
+
+    def init_state(seed: int = 0):
+        return jit_init(jax.random.key(seed))
+
+    jit_step = jax.jit(step, donate_argnums=(0, 1))
     return jit_step, init_state, orders
 
 

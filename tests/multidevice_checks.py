@@ -20,7 +20,6 @@ from repro.comms.hierarchical import (  # noqa: E402
 )
 from repro.comms.schedule_bridge import themis_axis_orders  # noqa: E402
 from repro.configs import ParallelConfig, TrainConfig, get_arch  # noqa: E402
-from repro.launch.compat import shard_map_compat  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
 
@@ -35,11 +34,11 @@ def check_chunked_all_reduce():
     x = jnp.asarray(np.random.default_rng(0).standard_normal((8, n)),
                     jnp.float32)
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         lambda xl: chunked_all_reduce(xl[0], [tuple(o) for o in orders],
                                       mean=False)[None],
         mesh=mesh, in_specs=P(("pod", "data", "model")),
-        out_specs=P(("pod", "data", "model")), check=False))
+        out_specs=P(("pod", "data", "model")), check_vma=False))
     out = np.asarray(f(x))
     want = np.asarray(x).sum(0)
     for row in out:
@@ -54,9 +53,9 @@ def check_int8_rs():
     x = jnp.asarray(np.random.default_rng(1).standard_normal((8, n)),
                     jnp.float32)
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         lambda xl: int8_reduce_scatter_axis(xl[0], "data")[None],
-        mesh=mesh, in_specs=P("data"), out_specs=P("data"), check=False))
+        mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False))
     out = np.asarray(f(x)).reshape(-1)
     want = np.asarray(x).sum(0)
     rel = np.abs(out - want) / (np.abs(want) + 1e-3)

@@ -93,6 +93,17 @@ SAMPLE_HLO = """
   %arst = (f32[8]{0}, f32[8]{0}) all-reduce-start(%z), replica_groups={}
 """
 
+# as the TPU compiler prints them: tiled layouts, async start/done pairs,
+# and collective names among the operands of other ops
+SAMPLE_TPU_HLO = """
+  %all-reduce.32 = f32[1024]{0:T(1024)} all-reduce(%slice.154), channel_id=36, replica_groups={{0,1},{2,3}}, use_global_device_ids=true
+  %copy-start.15 = (f32[1024]{0:T(1024)S(1)}, f32[1024]{0:T(1024)}, u32[]{:S(2)}) copy-start(%all-reduce.32)
+  %collective-permute-start.1 = (s32[2,1024]{1,0:T(2,128)}, s32[2,1024]{1,0:T(2,128)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%f.9), channel_id=3, source_target_pairs={{0,0},{1,2},{2,1},{3,3}}
+  %collective-permute-done.1 = s32[2,1024]{1,0:T(2,128)} collective-permute-done(%collective-permute-start.1)
+  %fusion.315 = (f32[]{:T(128)}, f32[2,1024]{1,0:T(2,128)S(1)}) fusion(%gather.11, %collective-permute-done.1), kind=kLoop
+  %all-gather-start = (bf16[256]{0:T(256)}, bf16[1024]{0:T(1024)}) all-gather-start(%p), replica_groups={{0,1,2,3}}, dimensions={0}
+"""
+
 
 def test_collective_stats_parses_hlo():
     s = collective_stats(SAMPLE_HLO)
@@ -102,3 +113,11 @@ def test_collective_stats_parses_hlo():
     assert s["bytes_by_kind"]["reduce-scatter"] == 256 * 4
     assert s["bytes_by_group_size"][4] == 16 * 512 * 2 + 256 * 4
     assert s["total_bytes"] > 0
+
+
+def test_collective_stats_parses_tpu_hlo():
+    s = collective_stats(SAMPLE_TPU_HLO)
+    assert s["op_counts"] == {"all-reduce": 1, "collective-permute": 1,
+                              "all-gather": 1}
+    assert s["bytes_by_kind"]["all-reduce"] == 1024 * 4
+    assert s["bytes_by_group_size"][2] == 1024 * 4

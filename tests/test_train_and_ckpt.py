@@ -1,7 +1,8 @@
 """Train-loop integration + fault tolerance (single device)."""
 import json
 import os
-import sys
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -116,19 +117,89 @@ def test_prefetcher_is_deterministic_and_resumable(tmp_path):
                                   np.asarray(got[2]["tokens"]))
 
 
-def test_train_driver_end_to_end(tmp_path, monkeypatch, capsys):
-    """The CLI driver trains a reduced model and reports decreasing loss."""
+def test_prefetcher_raises_worker_failure_at_next():
+    """A failed device transfer in the worker surfaces at next() instead of
+    leaving the consumer blocked on an empty queue."""
+    class Broken(Prefetcher):
+        def _shard(self, batch):
+            raise RuntimeError("device_put failed")
+
+    pf = Broken(SyntheticLM(101, 4, 16, seed=3),
+                make_mesh((1, 1), ("data", "model")))
+    got = []
+
+    def consume():
+        try:
+            next(pf)
+        except RuntimeError as e:
+            got.append(e)
+
+    t = threading.Thread(target=consume, daemon=True)
+    t.start()
+    t.join(timeout=30)
+    pf.close()
+    assert not t.is_alive(), "next() blocked after the worker failed"
+    assert got and "device_put failed" in str(got[0])
+    with pytest.raises(RuntimeError, match="device_put failed"):
+        next(pf)  # and again on every later call
+
+
+def test_prefetcher_transfers_each_batch_once():
+    """While the queue is full the worker waits with its next batch instead
+    of transferring it to the device again."""
+    calls = []
+
+    class Counting(Prefetcher):
+        def _shard(self, batch):
+            calls.append(1)
+            return super()._shard(batch)
+
+    pf = Counting(SyntheticLM(101, 4, 16, seed=3),
+                  make_mesh((1, 1), ("data", "model")), depth=1)
+    time.sleep(2.0)  # queue full for several put timeouts
+    n_idle = len(calls)
+    steps = [next(pf)[0] for _ in range(3)]
+    pf.close()
+    assert n_idle <= 2  # one queued, one waiting
+    assert steps == [0, 1, 2]
+
+
+def _train_driver_end_to_end(tmp_path, dp_sync):
     from repro.launch import train as train_mod
 
-    argv = ["train", "--arch", "qwen2.5-3b", "--reduced", "--steps", "10",
+    argv = ["--arch", "qwen2.5-3b", "--reduced", "--steps", "10",
             "--batch", "4", "--seq", "32", "--mesh", "1x1", "--lr", "1e-2",
             "--ckpt-dir", str(tmp_path / "ck"), "--ckpt-every", "5",
-            "--log-every", "5"]
-    monkeypatch.setattr(sys, "argv", argv)
-    losses = train_mod.main()
+            "--log-every", "5", "--dp-sync", dp_sync]
+    losses = train_mod.main(argv)
     assert len(losses) == 10
     # fresh uniform-random batches each step: loss plateaus at ~ln(vocab);
     # assert it stays finite and does not blow up.
     assert all(np.isfinite(l) for l in losses)
     assert losses[-1] < losses[0] + 0.5
     assert latest_step(str(tmp_path / "ck")) == 10
+
+
+def test_train_driver_end_to_end(tmp_path):
+    """The CLI driver trains a reduced model and reports decreasing loss."""
+    _train_driver_end_to_end(tmp_path, "gspmd")
+
+
+def test_train_driver_end_to_end_themis(tmp_path):
+    """The same through the Themis ZeRO-2 step on a 1x1 mesh (no mesh axes:
+    no collectives, world = 1)."""
+    _train_driver_end_to_end(tmp_path, "themis")
+
+
+def test_themis_and_gspmd_losses_agree_on_one_device():
+    """With world = 1 the Themis ZeRO-2 step and the GSPMD step do the same
+    math: same init, data and AdamW, so the same losses step for step."""
+    from repro.launch import train as train_mod
+
+    argv = ["--arch", "qwen2.5-3b", "--reduced", "--layers", "1",
+            "--steps", "4", "--batch", "2", "--seq", "16", "--mesh", "1x1",
+            "--lr", "1e-2", "--log-every", "4"]
+    themis = train_mod.main(argv + ["--dp-sync", "themis"])
+    gspmd = train_mod.main(argv + ["--dp-sync", "gspmd"])
+    assert len(themis) == 4
+    np.testing.assert_allclose(themis, gspmd, rtol=1e-5)
