@@ -10,7 +10,8 @@ each other exactly and the element layout round-trips with no index
 bookkeeping.
 
 These functions must run inside a ``shard_map`` that is *manual* over every
-axis in the chunk orders.
+axis in the chunk orders.  Each hop runs under ``jax.named_scope`` named
+``rs_<axis>`` or ``ag_<axis>``, so a profile shows the axis of every hop.
 """
 from __future__ import annotations
 
@@ -48,7 +49,8 @@ def chunked_reduce_scatter(
     for i, order in enumerate(orders):
         y = chunks[i]
         for ax in order:
-            y = jax.lax.psum_scatter(y, ax, scatter_dimension=0, tiled=True)
+            with jax.named_scope(f"rs_{ax}"):
+                y = jax.lax.psum_scatter(y, ax, scatter_dimension=0, tiled=True)
         out.append(y)
     return out
 
@@ -60,7 +62,8 @@ def chunked_all_gather(
     out = []
     for y, order in zip(shards, orders):
         for ax in reversed(order):
-            y = jax.lax.all_gather(y, ax, axis=0, tiled=True)
+            with jax.named_scope(f"ag_{ax}"):
+                y = jax.lax.all_gather(y, ax, axis=0, tiled=True)
         out.append(y)
     return jnp.stack(out)  # (C, L)
 
@@ -108,6 +111,7 @@ def chunked_reduce_scatter_int8(chunks, orders):
     for i, order in enumerate(orders):
         y = chunks[i]
         for ax in order:
-            y = int8_reduce_scatter_axis(y, ax)
+            with jax.named_scope(f"rs_{ax}"):
+                y = int8_reduce_scatter_axis(y, ax)
         out.append(y)
     return out

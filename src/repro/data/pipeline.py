@@ -14,6 +14,7 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding
 
 from repro.sharding.specs import batch_pspec
@@ -40,6 +41,8 @@ class Prefetcher:
 
     Each batch is transferred to the device once.  An exception raised in
     the worker (a failed transfer, say) is raised again by ``next()``.
+    Producing a batch (``batch_at`` and the transfer) is a ``data.produce``
+    span in a profile.
     """
 
     def __init__(self, dataset: SyntheticLM, mesh: Mesh, start_step: int = 0,
@@ -76,7 +79,11 @@ class Prefetcher:
     def _worker(self):
         step = self._step
         try:
-            while self._put((step, self._shard(self.dataset.batch_at(step)))):
+            while True:
+                with TraceAnnotation("data.produce"):
+                    item = (step, self._shard(self.dataset.batch_at(step)))
+                if not self._put(item):
+                    break
                 step += 1
         except Exception as e:  # handed to the consumer, raised by next()
             self._put(e)

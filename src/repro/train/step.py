@@ -14,6 +14,12 @@ Two data-parallel gradient-sync modes:
   ``hier_baseline`` pins the static dim1->dimD order for every chunk
   (paper Sec. 2.3) — the reproduction baseline.  Optional int8-on-the-wire
   reduce-scatter with per-device error feedback.
+
+Both modes name their phases with ``jax.named_scope``: ``forward`` (and so
+``transpose(jvp(forward))`` for the backward) and ``optimizer``; the Themis
+mode adds ``themis_flatten``, ``themis_rs``, ``themis_ag`` and
+``themis_unravel``.  The names reach the compiled HLO's ``op_name``
+metadata and cost nothing at run time.
 """
 from __future__ import annotations
 
@@ -38,6 +44,13 @@ from repro.models.common import mesh_context
 from repro.models.registry import ModelApi, count_params
 from repro.sharding.specs import batch_pspec, opt_state_pspec, param_shardings
 from repro.train.optimizer import adamw_init, adamw_update, clip_by_global_norm, lr_schedule
+
+
+def _forward(api: ModelApi, params, batch):
+    """The loss under the ``forward`` scope: differentiated, its ops are named
+    ``jvp(forward)`` and the backward's ``transpose(jvp(forward))``."""
+    with jax.named_scope("forward"):
+        return api.loss_fn(params, batch)
 
 
 # --------------------------------------------------------------------------
@@ -71,7 +84,7 @@ def make_gspmd_train_step(
         collectives of microbatch i overlap microbatch i+1's backward under
         XLA's async scheduler)."""
         if n_micro == 1:
-            return jax.value_and_grad(lambda p: api.loss_fn(p, batch))(params)
+            return jax.value_and_grad(lambda p: _forward(api, p, batch))(params)
         micro = jax.tree.map(
             lambda x: x.reshape((n_micro, x.shape[0] // n_micro) + x.shape[1:]),
             batch,
@@ -79,7 +92,7 @@ def make_gspmd_train_step(
 
         def body(acc, mb):
             loss_i, g_i = jax.value_and_grad(
-                lambda p: api.loss_fn(p, mb)
+                lambda p: _forward(api, p, mb)
             )(params)
             acc_loss, acc_g = acc
             return (acc_loss + loss_i,
@@ -94,8 +107,9 @@ def make_gspmd_train_step(
     def step(params, opt_state, batch):
         with mesh_context(mesh, sp=parallel.seq_sharding):
             loss, grads = grads_of(params, batch)
-            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-            new_params, new_opt, lr = adamw_update(grads, opt_state, params, tcfg)
+            with jax.named_scope("optimizer"):
+                grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+                new_params, new_opt, lr = adamw_update(grads, opt_state, params, tcfg)
             return new_params, new_opt, {"loss": loss, "gnorm": gnorm, "lr": lr}
 
     jit_step = jax.jit(
@@ -169,44 +183,49 @@ def make_themis_train_step(
     shard_spec = P(None, dp_axes)  # (C, per_chunk) scattered layout
 
     def step_shard(params, master, m, v, count, err, batch):
-        loss, grads = jax.value_and_grad(lambda p: api.loss_fn(p, batch))(params)
-        flat, unravel = ravel_pytree(grads)
-        flat = flat.astype(jnp.float32)
-        new_err = err
-        if use_int8:
-            flat = flat + err[0]
-            q, s = _quantize(flat)
-            new_err = (flat - q.astype(jnp.float32) * s)[None]
-        chunks = jnp.pad(flat, (0, pad_total)).reshape(n_chunks, per_chunk)
-        rs = (chunked_reduce_scatter_int8 if use_int8 else chunked_reduce_scatter)(
-            chunks, orders
-        )
-        g_shard = jnp.stack(rs) / world                        # (C, shard_len)
+        loss, grads = jax.value_and_grad(lambda p: _forward(api, p, batch))(params)
+        with jax.named_scope("themis_flatten"):
+            flat, unravel = ravel_pytree(grads)
+            flat = flat.astype(jnp.float32)
+            new_err = err
+            if use_int8:
+                flat = flat + err[0]
+                q, s = _quantize(flat)
+                new_err = (flat - q.astype(jnp.float32) * s)[None]
+            chunks = jnp.pad(flat, (0, pad_total)).reshape(n_chunks, per_chunk)
+        with jax.named_scope("themis_rs"):
+            rs = (chunked_reduce_scatter_int8 if use_int8 else chunked_reduce_scatter)(
+                chunks, orders
+            )
+            g_shard = jnp.stack(rs) / world                    # (C, shard_len)
 
-        # global-norm clip across the scattered shards
-        sq = jnp.sum(jnp.square(g_shard))
-        for a in axes:
-            sq = jax.lax.psum(sq, a)
-        gnorm = jnp.sqrt(sq)
-        g_shard = g_shard * jnp.minimum(1.0, tcfg.grad_clip / jnp.maximum(gnorm, 1e-9))
+        with jax.named_scope("optimizer"):
+            # global-norm clip across the scattered shards
+            sq = jnp.sum(jnp.square(g_shard))
+            for a in axes:
+                sq = jax.lax.psum(sq, a)
+            gnorm = jnp.sqrt(sq)
+            g_shard = g_shard * jnp.minimum(1.0, tcfg.grad_clip / jnp.maximum(gnorm, 1e-9))
 
-        # ZeRO-2 AdamW on fp32 master shards
-        count2 = count + 1
-        lr = lr_schedule(tcfg, count2)
-        b1, b2 = tcfg.beta1, tcfg.beta2
-        c1 = 1.0 - b1 ** count2.astype(jnp.float32)
-        c2 = 1.0 - b2 ** count2.astype(jnp.float32)
-        m2 = b1 * m + (1 - b1) * g_shard
-        v2 = b2 * v + (1 - b2) * jnp.square(g_shard)
-        upd = (m2 / c1) / (jnp.sqrt(v2 / c2) + tcfg.eps) + tcfg.weight_decay * master
-        master2 = master - lr * upd
+            # ZeRO-2 AdamW on fp32 master shards
+            count2 = count + 1
+            lr = lr_schedule(tcfg, count2)
+            b1, b2 = tcfg.beta1, tcfg.beta2
+            c1 = 1.0 - b1 ** count2.astype(jnp.float32)
+            c2 = 1.0 - b2 ** count2.astype(jnp.float32)
+            m2 = b1 * m + (1 - b1) * g_shard
+            v2 = b2 * v + (1 - b2) * jnp.square(g_shard)
+            upd = (m2 / c1) / (jnp.sqrt(v2 / c2) + tcfg.eps) + tcfg.weight_decay * master
+            master2 = master - lr * upd
 
-        # all-gather updated params (compute dtype on the wire)
-        p_dtype = jax.tree.leaves(params)[0].dtype
-        gathered = chunked_all_gather(
-            [master2[i].astype(p_dtype) for i in range(n_chunks)], orders
-        )
-        new_params = unravel(gathered.reshape(-1)[:n_params])
+        with jax.named_scope("themis_ag"):
+            # all-gather updated params (compute dtype on the wire)
+            p_dtype = jax.tree.leaves(params)[0].dtype
+            gathered = chunked_all_gather(
+                [master2[i].astype(p_dtype) for i in range(n_chunks)], orders
+            )
+        with jax.named_scope("themis_unravel"):
+            new_params = unravel(gathered.reshape(-1)[:n_params])
         for a in axes:
             loss = jax.lax.pmean(loss, a)
         return (new_params, master2, m2, v2, count2, new_err,

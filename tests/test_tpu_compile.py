@@ -3,13 +3,17 @@
 The TPU compiler refuses what interpret mode and the CPU backend accept:
 unaligned blocks, primitives Mosaic cannot lower, programs that do not fit
 HBM.  These tests compile the Pallas kernels at real widths and the Themis
-step on one chip and on a 2x2 mesh.  Nothing runs.
+step on one chip and on a 2x2 mesh, and check the names the step's ops
+carry for a profile (``bench/scopes.py`` reads them).  Nothing runs.
 
 Only one process at a time may load the TPU library, so the topology is
 described inside a fixture (never at import) and every test that needs it
 stays in this file.
 """
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +25,12 @@ from repro.configs import ParallelConfig, TrainConfig, get_arch
 from repro.kernels import ops
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
+from repro.models.registry import count_params
 from repro.sharding.specs import batch_pspec
 from repro.train.step import make_themis_train_step
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench import scopes  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -99,3 +107,71 @@ def test_themis_step_compiles(topo, data, model):
     else:
         assert {frozenset(o) for o in orders} == {frozenset({"data", "model"})}
         assert ops_by_kind
+
+
+@pytest.fixture(scope="module")
+def themis_2x2(topo):
+    """The reduced qwen2.5-3b Themis step lowered for the 2x2 mesh, its mesh,
+    its chunk orders and its flat-vector sizes."""
+    mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices[:4])
+    api = build_model(get_arch("qwen2.5-3b", reduced=True))
+    step, init_state, orders = make_themis_train_step(
+        api, mesh, ParallelConfig(data=2, model=2, dp_sync="themis"),
+        TrainConfig(total_steps=5, warmup_steps=1))
+    params, opt = jax.eval_shape(init_state)
+    tok = jax.ShapeDtypeStruct(
+        (16, 64), jnp.int32, sharding=NamedSharding(mesh, batch_pspec((16, 64), mesh, 16)))
+    lowered = step.lower(params, opt, {"tokens": tok, "labels": tok})
+    n_params = count_params(api.param_spec())
+    per_chunk = -(-n_params // (len(orders) * 4)) * 4
+    return lowered, mesh, orders, n_params, per_chunk
+
+
+def test_themis_hops_name_their_axis(themis_2x2):
+    """Each chunk's reduce-scatter and all-gather hop runs under
+    ``rs_<axis>`` / ``ag_<axis>``, and that axis is the one its replica
+    groups span; the hops follow the chunk orders."""
+    lowered, mesh, orders, _, _ = themis_2x2
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    hops = {"rs": [], "ag": []}
+    for line in text.splitlines():
+        kind = re.search(r" (reduce-scatter|all-gather)\(", line)
+        if not kind:
+            continue
+        ax = scopes.axes_spanned(scopes.replica_groups(line), mesh.devices.shape,
+                                 mesh.axis_names)
+        scope = [p for p in scopes.OP_NAME.search(line).group(1).split("/")
+                 if re.fullmatch(r"(rs|ag)_\w+", p)]
+        prefix = "rs" if kind.group(1) == "reduce-scatter" else "ag"
+        assert len(ax) == 1 and scope == [f"{prefix}_{ax[0]}"], line[:300]
+        hops[prefix].append(ax[0])
+    assert hops["rs"] == [a for o in orders for a in o]
+    assert hops["ag"] == [a for o in orders for a in reversed(o)]
+
+
+def test_themis_flat_path_lies_under_step_scopes(themis_2x2):
+    """In the compiled step, every instruction over the flat gradient, the
+    chunk buffer or a chunk's shards belongs to a named phase, also where
+    the compiler made it and dropped its metadata; each collective spans
+    one mesh axis, and the chunk collectives are the RS and AG phases'."""
+    lowered, mesh, orders, n_params, per_chunk = themis_2x2
+    text = lowered.compile().as_text()
+    names = scopes.hlo_map(text, mesh.devices.shape, mesh.axis_names)
+    flat = {n_params, len(orders) * per_chunk, per_chunk, per_chunk // 2, per_chunk // 4}
+    seen = 0
+    for line in text.splitlines():
+        m = scopes.INSTR.match(line)
+        shape = re.match(r"\s*(?:ROOT )?%?[\w.-]+ = \(?\w+\[([\d,]*)\]", line)
+        if not (m and shape) or re.search(r" (parameter|get-tuple-element|constant)\(", line):
+            continue
+        dims = {int(d) for d in shape.group(1).split(",") if d}
+        if dims & flat:
+            seen += 1
+            assert scopes.phase_of(names[m.group(1)][0]) != "unscoped", line[:300]
+    assert seen
+    chunk_phases = set()
+    for name, (op, axes) in names.items():
+        if re.match(r"(all-reduce|all-gather|reduce-scatter)(-start)?(\.\d+)?$", name):
+            assert len(axes) == 1, name
+            chunk_phases.add(scopes.phase_of(op))
+    assert {"themis_rs", "themis_ag"} <= chunk_phases
