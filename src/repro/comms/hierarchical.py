@@ -28,13 +28,65 @@ def world_size(axes: tuple[str, ...]) -> int:
     return math.prod(jax.lax.axis_size(a) for a in axes)
 
 
+# f32 elements in one (8, 128) TPU tile: a flat vector is laid out in
+# 1024-element tiles, a 2-D buffer in (8, 128) ones
+TILE = 1024
+
+
+def chunk_len(n: int, n_chunks: int, world: int) -> int:
+    """Length of each of ``n_chunks`` chunks that together hold ``n``
+    elements: a multiple of ``world * TILE``, so that every device's shard
+    of a chunk is whole f32 tiles.  The padding is at most
+    ``n_chunks * world * TILE`` elements, all of it at the tail."""
+    unit = world * TILE
+    return -(-n // (n_chunks * unit)) * unit
+
+
+def _pieces(sizes: list[int], per_chunk: int):
+    """Where the parts of ``sizes``, laid end to end, fall in rows of
+    ``per_chunk``: ``(part, start, stop, row, row_start)`` per piece."""
+    at = 0
+    for i, n in enumerate(sizes):
+        a = 0
+        while a < n:
+            row, col = divmod(at + a, per_chunk)
+            b = min(n, a + per_chunk - col)
+            yield i, a, b, row, col
+            a = b
+        at += n
+
+
+def split_chunks(parts: list[jax.Array], n_chunks: int, per_chunk: int) -> jax.Array:
+    """``(n_chunks, per_chunk)``: the 1-D ``parts`` laid end to end, cut into
+    rows and zero-padded at the tail.  Each row is concatenated from static
+    slices of the parts, so no flat vector is built, and none is reshaped:
+    the TPU compiler turns the reshape of a flat vector into rows into a
+    row-by-row loop where a row is not whole tiles."""
+    dtype = parts[0].dtype
+    rows = [[] for _ in range(n_chunks)]
+    for i, a, b, row, _ in _pieces([p.shape[0] for p in parts], per_chunk):
+        rows[row].append(parts[i][a:b])
+    for r in rows:
+        fill = per_chunk - sum(x.shape[0] for x in r)
+        if fill:
+            r.append(jnp.zeros((fill,), dtype))
+    return jnp.stack([jnp.concatenate(r) for r in rows])
+
+
+def join_chunks(chunks: jax.Array, sizes: list[int]) -> list[jax.Array]:
+    """Inverse of ``split_chunks``: the 1-D parts of ``sizes``, each
+    concatenated from static slices of the rows."""
+    per_chunk = chunks.shape[1]
+    parts = [[] for _ in sizes]
+    for i, a, b, row, col in _pieces(sizes, per_chunk):
+        parts[i].append(chunks[row, col:col + b - a])
+    return [jnp.concatenate(p) if p else chunks[0, :0] for p in parts]
+
+
 def pad_to_chunks(flat: jax.Array, n_chunks: int, axes: tuple[str, ...]):
-    """Pad a flat vector so it splits into n_chunks divisible by the world."""
-    world = world_size(axes)
+    """Split a flat vector into ``n_chunks`` whose shards are whole tiles."""
     n = flat.shape[0]
-    per = -(-n // (n_chunks * world)) * world
-    padded = jnp.pad(flat, (0, n_chunks * per - n))
-    return padded.reshape(n_chunks, per), n
+    return split_chunks([flat], n_chunks, chunk_len(n, n_chunks, world_size(axes))), n
 
 
 def chunked_reduce_scatter(
@@ -78,8 +130,7 @@ def chunked_all_reduce(
     if mean:
         w = world_size(axes)
         shards = [s / w for s in shards]
-    gathered = chunked_all_gather(shards, orders)
-    return gathered.reshape(-1)[:n]
+    return join_chunks(chunked_all_gather(shards, orders), [n])[0]
 
 
 # -- int8-on-the-wire reduce-scatter (beyond paper: gradient compression) ----

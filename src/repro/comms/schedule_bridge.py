@@ -139,7 +139,7 @@ _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _OP_RE = re.compile(
     r"=\s*(?:\(.*?\)|\w+\[[\d,]*\](?:\{[^}]*\})?)\s*"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start|-done)?\("
+    r"(-start|-done)?\("
 )
 _GROUPS_RE = re.compile(r"replica_groups=\{?\{([\d,]+)\}")
 
@@ -172,7 +172,9 @@ def collective_stats(hlo_text: str) -> dict:
     counts: dict[str, int] = defaultdict(int)
     for line in hlo_text.splitlines():
         m = _OP_RE.search(line)
-        if not m or "-done" in line:
+        # an async pair counts once, at its start; an operand's name (such
+        # as %dynamic-slice-done.3) does not make an op a "-done"
+        if not m or m.group(2) == "-done":
             continue
         kind = m.group(1)
         nbytes = _line_output_bytes(line)

@@ -6,7 +6,9 @@ Two data-parallel gradient-sync modes:
   with GSPMD shardings; XLA inserts all collectives (TP/EP/FSDP included).
 * ``themis`` / ``hier_baseline`` — the paper's technique as a first-class
   feature: the entire step runs in a ``shard_map`` manual over every mesh
-  axis (pure-DP ZeRO-2).  Gradients are flattened, chunked, and
+  axis (pure-DP ZeRO-2).  The gradient leaves, laid end to end in
+  ``ravel_pytree`` order, are cut into chunks whose per-device shards are
+  whole f32 tiles (``comms.hierarchical.chunk_len``), and
   reduce-scattered with per-chunk axis orders from the Themis scheduler
   (trace-time Algorithm 1); the sharded AdamW update runs on each device's
   scattered shard against fp32 master shards; updated parameters are
@@ -28,15 +30,17 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.comms.hierarchical import (
     _quantize,
+    chunk_len,
     chunked_all_gather,
     chunked_reduce_scatter,
     chunked_reduce_scatter_int8,
+    join_chunks,
+    split_chunks,
 )
 from repro.comms.schedule_bridge import themis_axis_orders
 from repro.configs.base import ParallelConfig, TrainConfig
@@ -153,6 +157,11 @@ def _local_shard(y: jax.Array, order: tuple[str, ...]) -> jax.Array:
     return y
 
 
+def _raveled(tree) -> list[jax.Array]:
+    """The leaves of ``tree`` as 1-D f32 arrays, in ``ravel_pytree`` order."""
+    return [x.reshape(-1).astype(jnp.float32) for x in jax.tree.leaves(tree)]
+
+
 def make_themis_train_step(
     api: ModelApi, mesh: Mesh, parallel: ParallelConfig, tcfg: TrainConfig
 ):
@@ -172,9 +181,7 @@ def make_themis_train_step(
     orders = [tuple(o) for o in
               themis_axis_orders(axis_sizes, n_params * 4, n_chunks, policy)]
 
-    per_chunk = -(-n_params // (n_chunks * world)) * world
-    shard_len = per_chunk // world
-    pad_total = n_chunks * per_chunk - n_params
+    per_chunk = chunk_len(n_params, n_chunks, world)
     use_int8 = parallel.compression == "int8"
 
     # one mesh axis -> its name; several -> the tuple; none (a single
@@ -185,19 +192,18 @@ def make_themis_train_step(
     def step_shard(params, master, m, v, count, err, batch):
         loss, grads = jax.value_and_grad(lambda p: _forward(api, p, batch))(params)
         with jax.named_scope("themis_flatten"):
-            flat, unravel = ravel_pytree(grads)
-            flat = flat.astype(jnp.float32)
+            chunks = split_chunks(_raveled(grads), n_chunks, per_chunk)
             new_err = err
             if use_int8:
-                flat = flat + err[0]
-                q, s = _quantize(flat)
-                new_err = (flat - q.astype(jnp.float32) * s)[None]
-            chunks = jnp.pad(flat, (0, pad_total)).reshape(n_chunks, per_chunk)
+                chunks = chunks + split_chunks([err[0]], n_chunks, per_chunk)
+                q, s = _quantize(chunks)
+                new_err = join_chunks(chunks - q.astype(jnp.float32) * s,
+                                      [n_params])[0][None]
         with jax.named_scope("themis_rs"):
             rs = (chunked_reduce_scatter_int8 if use_int8 else chunked_reduce_scatter)(
                 chunks, orders
             )
-            g_shard = jnp.stack(rs) / world                    # (C, shard_len)
+            g_shard = jnp.stack(rs) / world            # (C, per_chunk / world)
 
         with jax.named_scope("optimizer"):
             # global-norm clip across the scattered shards
@@ -225,7 +231,10 @@ def make_themis_train_step(
                 [master2[i].astype(p_dtype) for i in range(n_chunks)], orders
             )
         with jax.named_scope("themis_unravel"):
-            new_params = unravel(gathered.reshape(-1)[:n_params])
+            leaves, treedef = jax.tree.flatten(params)
+            parts = join_chunks(gathered, [x.size for x in leaves])
+            new_params = treedef.unflatten(
+                [x.reshape(p.shape).astype(p.dtype) for x, p in zip(parts, leaves)])
         for a in axes:
             loss = jax.lax.pmean(loss, a)
         return (new_params, master2, m2, v2, count2, new_err,
@@ -249,9 +258,8 @@ def make_themis_train_step(
         return new_p, {"master": master2, "m": m2, "v": v2, "count": c2,
                        "err": err2}, metrics
 
-    def build_master(pf):
-        chunks = jnp.pad(pf.astype(jnp.float32), (0, pad_total)).reshape(
-            n_chunks, per_chunk)
+    def build_master(params):
+        chunks = split_chunks(_raveled(params), n_chunks, per_chunk)
         return jnp.stack([_local_shard(chunks[i], orders[i])
                           for i in range(n_chunks)])
 
@@ -259,7 +267,7 @@ def make_themis_train_step(
         params = api.init(key)
         master = jax.shard_map(build_master, mesh=mesh, in_specs=P(),
                                out_specs=shard_spec,
-                               check_vma=False)(ravel_pytree(params)[0])
+                               check_vma=False)(params)
         err_shape = (world, n_params) if use_int8 else ()
         opt = {"master": master, "m": jnp.zeros_like(master),
                "v": jnp.zeros_like(master),
@@ -268,7 +276,7 @@ def make_themis_train_step(
         return params, opt
 
     # One program that lays every buffer out on the mesh: params replicated,
-    # optimizer state scattered.  The raveled copy of the params is a
+    # optimizer state scattered.  The chunked copy of the params is a
     # temporary of this program and is freed when it returns.
     rep = NamedSharding(mesh, P())
     scat = NamedSharding(mesh, shard_spec)

@@ -128,6 +128,62 @@ def check_int8_themis_step_trains():
     print(f"int8 themis step OK ({losses[0]:.3f} -> {losses[-1]:.3f})")
 
 
+def check_chunk_geometry_2x2():
+    """On a 2x2 mesh every device's shard of a chunk is whole f32 tiles,
+    and the first moment after one step, read back through the layout
+    ``bench/check.py`` reads, is (1 - beta1) times the clipped gradient of
+    each leaf."""
+    import math
+    import sys
+    from pathlib import Path
+
+    from repro.comms.hierarchical import TILE
+    from repro.models.registry import count_params
+    from repro.train.step import make_themis_train_step
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench import check
+
+    mesh = make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+    # f32 compute: in bf16 the devices' quarter batches round otherwise
+    # than the whole batch of the reference
+    cfg = get_arch("qwen2.5-3b", reduced=True).replace(remat=False, dtype="float32")
+    api = build_model(cfg)
+    tcfg = TrainConfig(learning_rate=1e-3, warmup_steps=1, total_steps=10)
+    step, init_state, orders = make_themis_train_step(
+        api, mesh, ParallelConfig(data=2, model=2, dp_sync="themis",
+                                  chunks_per_collective=4), tcfg)
+    assert len(set(orders)) == 2, orders  # both axis orders are exercised
+    params, opt = init_state(0)
+    n = count_params(api.param_spec())
+    n_chunks, per_chunk = opt["m"].shape
+    assert per_chunk % (4 * TILE) == 0 and n_chunks * per_chunk >= n
+    assert n_chunks * per_chunk - n < n_chunks * 4 * TILE
+
+    rng = np.random.default_rng(0)
+    tok = rng.integers(0, cfg.vocab_size, (8, 17), dtype=np.int32)
+    batch = {"tokens": jnp.asarray(tok[:, :-1]), "labels": jnp.asarray(tok[:, 1:])}
+    g = jax.tree.map(lambda x: np.asarray(x, np.float64),
+                     jax.grad(api.loss_fn)(params, batch))
+    gnorm = math.sqrt(sum(float(np.sum(x * x)) for x in jax.tree.leaves(g)))
+    scale = (1 - tcfg.beta1) * min(1.0, tcfg.grad_clip / gnorm)
+    like = check.by_path(params)
+    _, opt, _ = step(params, opt, batch)
+    spec = opt["m"].sharding.spec
+    flat = check.themis_flat(np.asarray(opt["m"]), orders, spec[1], dict(mesh.shape))
+    assert not flat[n:].any(), "padding is not at the tail alone"
+    got = check.split_flat(flat, like)
+    want = {k: scale * w.reshape(-1) for k, w in check.by_path(g).items()}
+    # a key bias has a gradient of round-off alone (softmax ignores it), so
+    # each gap is taken over the larger of its leaf's norm and the median's
+    median = float(np.median([check.norm(w) for w in want.values()]))
+    for k, w in want.items():
+        gap = check.norm(got[k] - w) / max(check.norm(w), median)
+        # the same sums in another order: f32 round-off
+        assert gap <= 1e-4, f"{k}: first moment off by {gap}"
+    print(f"chunk geometry 2x2 OK (per_chunk {per_chunk}, orders {sorted(set(orders))})")
+
+
 def check_pipeline_parallel():
     from repro.models import transformer as tr
     from repro.train.pipeline import make_pipeline_loss
@@ -178,6 +234,7 @@ if __name__ == "__main__":
     check_int8_rs()
     check_themis_step_matches_gspmd()
     check_int8_themis_step_trains()
+    check_chunk_geometry_2x2()
     check_pipeline_parallel()
     check_sharded_serving()
     print("ALL MULTIDEVICE CHECKS PASSED")

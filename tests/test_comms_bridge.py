@@ -121,3 +121,14 @@ def test_collective_stats_parses_tpu_hlo():
                               "all-gather": 1}
     assert s["bytes_by_kind"]["all-reduce"] == 1024 * 4
     assert s["bytes_by_group_size"][2] == 1024 * 4
+
+
+def test_collective_stats_counts_ops_that_take_a_done():
+    """A reduce-scatter hop as the TPU compiler makes it: an all-reduce of
+    the output of an async ``dynamic-slice-done``.  It is an all-reduce,
+    not the "-done" half of an async pair."""
+    line = ("  %all-reduce.32 = f32[16951296]{0:T(1024)} all-reduce("
+            "%dynamic-slice-done.2), channel_id=68, replica_groups={{0,1},{2,3}}")
+    s = collective_stats(line + "\n" + line.replace(".32", ".33"))
+    assert s["op_counts"] == {"all-reduce": 2}
+    assert s["bytes_by_group_size"][2] == 2 * 16951296 * 4

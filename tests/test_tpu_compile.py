@@ -109,6 +109,56 @@ def test_themis_step_compiles(topo, data, model):
         assert ops_by_kind
 
 
+def _result_shapes(line: str) -> list[set[int]]:
+    """The dimensions of each result shape of an HLO instruction (several
+    where the compiler combined small collectives into one tuple)."""
+    shape = re.match(r"\s*(?:ROOT )?%?[\w.-]+ = (.*?) [\w-]+\(", line)
+    return [{int(d) for d in dims.split(",") if d}
+            for dims in re.findall(r"\[([\d,]*)\]", shape.group(1))] if shape else []
+
+
+@pytest.mark.parametrize("data,model", [(1, 1), (2, 2)])
+def test_themis_step_has_no_layout_loop(topo, data, model):
+    """The flat gradient becomes the chunk buffer, and the gathered chunks
+    the parameters, with no ``while`` loop over either: each device's shard
+    of a chunk is whole (8, 128) f32 tiles.  On 2x2 every all-gather hop
+    of a chunk is an all-gather (a reduce-scatter hop is still an
+    all-reduce and a slice): one of each per chunk and axis.
+
+    The vocabulary is widened to 65536 (4.3M parameters): below a few
+    million elements the compiler copies even an unaligned layout without
+    a loop, and a chunk that holds only padding is folded away."""
+    mesh = make_mesh((data, model), ("data", "model"),
+                     devices=topo.devices[: data * model])
+    api = build_model(get_arch("qwen2.5-3b", reduced=True).replace(vocab_size=65536))
+    step, init_state, orders = make_themis_train_step(
+        api, mesh, ParallelConfig(data=data, model=model, dp_sync="themis"),
+        TrainConfig(total_steps=5, warmup_steps=1))
+    params, opt = jax.eval_shape(init_state)
+    n_chunks, per_chunk = opt["m"].shape
+    assert n_chunks == 16
+    n_params = count_params(api.param_spec())
+    assert (n_chunks - 1) * per_chunk < n_params
+    gb = 4 * data * model
+    tok = jax.ShapeDtypeStruct(
+        (gb, 64), jnp.int32,
+        sharding=NamedSharding(mesh, batch_pspec((gb, 64), mesh, gb)))
+    text = step.lower(params, opt, {"tokens": tok, "labels": tok}).compile().as_text()
+    flat = {n_params, n_chunks * per_chunk, per_chunk}
+    loops = [line for line in text.splitlines() if re.search(r" while\(", line)]
+    assert loops  # the model's own loops: the layer scan, attention
+    for line in loops:
+        assert not any(dims & flat for dims in _result_shapes(line)), line[:300]
+    hops = {"all-gather": 0, "all-reduce": 0}
+    for line in text.splitlines():
+        kind = re.search(r" (all-gather|all-reduce)(-start)?\(", line)
+        if kind:
+            hops[kind.group(1)] += sum(bool(dims & {per_chunk, per_chunk // 2})
+                                       for dims in _result_shapes(line))
+    hops_per_kind = n_chunks * sum(n > 1 for n in (data, model))
+    assert hops == {"all-gather": hops_per_kind, "all-reduce": hops_per_kind}
+
+
 @pytest.fixture(scope="module")
 def themis_2x2(topo):
     """The reduced qwen2.5-3b Themis step lowered for the 2x2 mesh, its mesh,
@@ -122,9 +172,7 @@ def themis_2x2(topo):
     tok = jax.ShapeDtypeStruct(
         (16, 64), jnp.int32, sharding=NamedSharding(mesh, batch_pspec((16, 64), mesh, 16)))
     lowered = step.lower(params, opt, {"tokens": tok, "labels": tok})
-    n_params = count_params(api.param_spec())
-    per_chunk = -(-n_params // (len(orders) * 4)) * 4
-    return lowered, mesh, orders, n_params, per_chunk
+    return lowered, mesh, orders, count_params(api.param_spec()), opt["m"].shape[1]
 
 
 def test_themis_hops_name_their_axis(themis_2x2):
