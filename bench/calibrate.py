@@ -5,13 +5,15 @@
         [--faults <k>] [--out <file.json>]
 
 For each of ``n`` seeds from ``s`` on, the program's first steps against
-the plain reference (the sound runs: the lower readings).  For the first
-``k`` of them, the control, which is the reference computed with float8
-products put in the program's place, and the faults that the cell can
-have, read against the same reference: half of the batch left out
-(planted in the reference), and on a cell of several chips the exchange
-between chips left out (planted in the program: each device keeps its own
-shard of its own gradient).  A step that returns its state unchanged reads
+the plain reference (the sound runs: the lower readings).  The reference
+is the module that the cell's configuration file names; its ``VARIANTS``
+are the sound reference, then the control, then the faults planted in
+the reference.  For ``bench/reference.py`` the control is the reference
+computed with float8 products, and the planted fault half of the batch
+left out.  For the first ``k`` seeds, the control and those faults put in
+the program's place, read against the same reference, and on a cell of
+several chips the exchange between chips left out (planted in the
+program: each device keeps its own shard of its own gradient).  A step that returns its state unchanged reads
 1 on ``grad_gap`` and ``change_gap`` by construction and needs no run.
 
 The benchmark's own runs never run this.
@@ -63,23 +65,20 @@ def main(argv=None) -> int:
     found = H.resolve(args.workload)
     config, traffic, chips = found["config"], found["traffic"], found["cell"]["chips"]
     devs, device = H.require_chips(chips)
-    import jax
-
-    from repro.launch.cache import enable_compile_cache
-
     from bench import check
-    from bench.reference import Reference
 
-    enable_compile_cache()
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    reference = H.config_module(config, "reference")
+    sound, control, *planted = reference.VARIANTS
+    H.enable_cache()
     cell = H.Cell(config, traffic, devs)
     refs = {}
-    out = {"workload": args.workload, "device": device, "sound": [], "control": [],
-           "half_batch": [], "no_exchange": [], "leaves": []}
+    kinds = ["sound", "control", *planted, "no_exchange"]
+    out = {"workload": args.workload, "device": device, "leaves": [],
+           **{kind: [] for kind in kinds}}
 
-    def ref_of(seed, variant="fp32"):
+    def ref_of(seed, variant=sound):
         if variant not in refs:
-            refs[variant] = Reference(config, traffic["train"], variant)
+            refs[variant] = reference.Reference(config, traffic["train"], variant)
         return H.reference_readings(refs[variant], config, traffic, chips, seed)
 
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
@@ -95,12 +94,12 @@ def main(argv=None) -> int:
         H.log(f"[sound] seed {seed} {nums} program {t1 - t0:.1f} s "
               f"reference {time.perf_counter() - t1:.1f} s")
     for seed in seeds[: args.faults]:
-        for variant in ("fp8", "half_batch"):
+        for variant in (control, *planted):
             got = ref_of(seed, variant)
             nums = check.numbers(got, truth[seed])
-            if variant == "fp8":
+            if variant == control:
                 out["leaves"].append({"seed": seed, "control": got})
-            out["control" if variant == "fp8" else variant].append({"seed": seed, **nums})
+            out["control" if variant == control else variant].append({"seed": seed, **nums})
             H.log(f"[{variant}] seed {seed} {nums}")
     if chips > 1 and args.faults:
         import repro.train.step as S
@@ -111,7 +110,7 @@ def main(argv=None) -> int:
             nums = check.numbers(sound_readings(broken, seed), truth[seed])
             out["no_exchange"].append({"seed": seed, **nums})
             H.log(f"[no_exchange] seed {seed} {nums}")
-    for kind in ("sound", "control", "half_batch", "no_exchange"):
+    for kind in kinds:
         rows_ = out[kind]
         if rows_:
             pick = max if kind == "sound" else min

@@ -6,7 +6,11 @@ Everything about a cell is found by name: the cell in ``BENCHMARK.json``,
 its configuration in the file that entry names, its traffic in
 ``bench/traffic/<traffic>.json``, its correctness limits in
 ``bench/limits/<cell>.json`` and each metric's reader in
-``bench/metrics/<metric>.py``.
+``bench/metrics/<metric>.py``.  The configuration file names the rest of
+what depends on the architecture: the program's settings it is checked
+against (``program.expect``), its plain reference module (``reference``)
+and the module that counts its model FLOPs (``flops``).  So a new
+architecture enters as new files.
 
 A run builds the program's train step for the cell (``make_train_step``),
 initialises its state on the device from the seed, and feeds it through
@@ -16,7 +20,8 @@ cache), warm up and are compared with the plain reference once the window
 has closed.  The window then steps the same state for ``--seconds``,
 waiting on the host only for a step two behind the newest, and ends with
 one ``block_until_ready``.  With ``--trace 1`` the window runs under the
-profiler and the run reports the per-layer metrics.
+profiler and the run reports the per-layer metrics, among them the
+device time of the step's named scopes (``bench/scopes.py``).
 """
 from __future__ import annotations
 
@@ -72,6 +77,17 @@ def metrics_for(bm: dict, workload: str, trace: bool) -> list[dict]:
     return [m for m in bm[kind] if workload in m.get("workloads", [workload])]
 
 
+def config_module(config: dict, key: str):
+    """The module that the configuration file names under ``key``
+    (``"reference": "bench/reference.py"``), as a path from the root of
+    the checkout."""
+    path = Path(config[key])
+    if path.is_absolute() or ".." in path.parts or path.suffix != ".py":
+        raise ValueError(f"{config['name']}: {key} {config[key]!r} is no module "
+                         f"path inside the checkout")
+    return importlib.import_module(".".join(path.with_suffix("").parts))
+
+
 def read_metric(name: str, record: dict):
     """The value the metric's own reader takes from the run's record."""
     spec = importlib.util.spec_from_file_location(
@@ -104,26 +120,35 @@ def peak_memory(devs) -> int:
 
 
 # -- the program under test -------------------------------------------------------------
+# program attribute <- key of a configuration file: every decoder file has these
+DECODER = {"d_model": "hidden_size", "num_heads": "num_attention_heads",
+           "num_layers": "num_hidden_layers", "vocab_size": "vocab_size",
+           "norm_eps": "rms_norm_eps", "rope_theta": "rope_theta",
+           "tie_embeddings": "tie_word_embeddings", "dtype": "compute_dtype",
+           "param_dtype": "param_dtype"}
+# ... and these are checked where the file states them
+STATED = {"d_ff": "intermediate_size", "num_kv_heads": "num_key_value_heads",
+          "resolved_head_dim": "head_dim"}
+
+
+def program_want(config: dict) -> dict:
+    """What the program's model configuration must hold for a benchmark
+    configuration file: the decoder sizes the file states, and the settings
+    of its architecture that the file lists under ``program.expect``."""
+    want = {attr: config[key] for attr, key in DECODER.items()}
+    want.update({attr: config[key] for attr, key in STATED.items()
+                 if config.get(key) is not None})
+    return {**want, **config["program"]["expect"]}
+
+
 def program_config(config: dict):
     """The program's model configuration for a benchmark configuration file,
-    checked against every size the file states."""
+    checked against ``program_want``; a difference raises."""
     from repro.configs import get_arch
 
     prog = config["program"]
     cfg = get_arch(prog["arch"]).replace(**prog.get("replace", {}))
-    want = {
-        "d_model": config["hidden_size"], "d_ff": config["intermediate_size"],
-        "num_heads": config["num_attention_heads"],
-        "num_kv_heads": config["num_key_value_heads"],
-        "resolved_head_dim": config.get("head_dim") or
-        config["hidden_size"] // config["num_attention_heads"],
-        "num_layers": config["num_hidden_layers"], "vocab_size": config["vocab_size"],
-        "norm_eps": config["rms_norm_eps"], "rope_theta": config["rope_theta"],
-        "tie_embeddings": config["tie_word_embeddings"],
-        "dtype": config["compute_dtype"], "param_dtype": config["param_dtype"],
-        # Qwen2ForCausalLM: biases on q/k/v, SwiGLU MLP, no experts
-        "family": "dense", "qkv_bias": True, "gated_mlp": True,
-    }
+    want = program_want(config)
     wrong = {k: (getattr(cfg, k), v) for k, v in want.items() if getattr(cfg, k) != v}
     if wrong:
         raise ValueError(f"program config differs from {config['name']}: {wrong}")
@@ -239,6 +264,21 @@ def reference_readings(ref, config: dict, traffic: dict, chips: int, seed: int) 
             "change": {k: float(v) for k, v in check.by_path(out["change"]).items()}}
 
 
+def enable_cache() -> str:
+    """JAX's persistent compilation cache in its fixed directory, keyed on
+    the programs' metadata too: the ``op_name`` of each instruction, which
+    the scopes of a traced run are read from, so that a program compiled
+    before the scopes were named is never read back.  Traced and untraced
+    runs read one executable."""
+    import jax
+
+    from repro.launch.cache import enable_compile_cache
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return enable_compile_cache()
+
+
 # -- the window ---------------------------------------------------------------------------
 class CompileCounter:
     """Counts the programs JAX compiles or reads from the persistent cache
@@ -291,10 +331,11 @@ def window(step, params, opt, pf, seconds: float):
 @contextlib.contextmanager
 def profiled(on: bool):
     """Run the body under the profiler when ``on``; yields a dict that
-    holds the trace's neutral record afterwards."""
+    holds the trace's neutral record afterwards, with the program's own
+    ``data.*`` spans apart under ``"data"``."""
     import jax
 
-    from bench import trace
+    from bench import scopes, trace
 
     out = {}
     if not on:
@@ -310,16 +351,29 @@ def profiled(on: bool):
         finally:
             jax.profiler.stop_trace()
         out["record"] = trace.load(tmp)
+        out["record"]["data"] = scopes.load_spans(tmp)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
+
+
+def trace_readings(tr: dict, ops_map: dict, steps: int) -> dict:
+    """What a traced window adds to the record the metric readers get: the
+    trace's reduction (``"trace"``) and the device and host ms per step of
+    the step's named scopes, mesh axes and ``data.*`` spans (``"scopes"``)."""
+    from bench import scopes, trace
+
+    return {"trace": trace.reduce(tr), "scopes": scopes.per_step_ms(tr, ops_map, steps)}
 
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-def run(workload: str, seed: int, seconds: float, trace_on: bool) -> dict:
-    """One run of one cell; returns the result line."""
+def measure(workload: str, seed: int, seconds: float, trace_on: bool) -> tuple[dict, dict]:
+    """One run of one cell: its result line, and the record the metrics
+    were read from.  A traced run's record also holds the trace's neutral
+    record (``"trace_record"``) and the compiled step's map of op names
+    (``"ops_map"``, ``bench/scopes.py``)."""
     t_proc = psutil.Process().create_time()
     found = resolve(workload)
     bm, cell_spec, config, traffic = (found[k] for k in
@@ -329,18 +383,17 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool) -> dict:
     import jax
 
     from repro.comms.schedule_bridge import collective_stats
-    from repro.launch.cache import enable_compile_cache
 
-    from bench import check, flops, peaks, trace
-    from bench.reference import Reference
+    from bench import check, peaks, scopes
 
-    log(f"[setup] cache dir {enable_compile_cache()}")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    reference, flops = config_module(config, "reference"), config_module(config, "flops")
+    log(f"[setup] cache dir {enable_cache()}")
     compiles = CompileCounter()
     cell = Cell(config, traffic, devs)
     params, opt, pf, raw = first_steps(cell, seed)
     cost = cell.compiled.cost_analysis()
-    log(f"[setup] step collectives {collective_stats(cell.compiled.as_text())['op_counts']}")
+    hlo = cell.compiled.as_text()
+    log(f"[setup] step collectives {collective_stats(hlo)['op_counts']}")
     log(f"[setup] first losses {raw['losses']}; programs compiled or read from the "
         f"cache {compiles.n}, of them cache reads {compiles.hits}")
 
@@ -356,7 +409,7 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool) -> dict:
     del params, opt
 
     prog = program_readings(cell, raw)
-    ref = Reference(config, traffic["train"])
+    ref = reference.Reference(config, traffic["train"])
     t_ref = time.perf_counter()
     refr = reference_readings(ref, config, traffic, cell_spec["chips"], seed)
     log(f"[reference] {time.perf_counter() - t_ref} s; losses {refr['losses']}")
@@ -370,8 +423,14 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool) -> dict:
         "memory_peak_bytes": peak_bytes, "peak": peaks.peak(device["kind"]),
         "model_flops_per_step": flops.train_step_flops(config, cell.global_batch, cell.seq),
         "compiled_bytes_per_step": cost["bytes accessed"],
-        "trace": trace.reduce(prof["record"]) if trace_on else None,
+        "trace": None, "scopes": None,
     }
+    if trace_on:
+        t_read = time.perf_counter()
+        ops_map = scopes.hlo_map(hlo, cell.mesh.devices.shape, cell.mesh.axis_names)
+        record.update(trace_readings(prof["record"], ops_map, len(losses)),
+                      trace_record=prof["record"], ops_map=ops_map)
+        log(f"[trace] read in {time.perf_counter() - t_read} s")
     metrics = {}
     for m in metrics_for(bm, workload, trace_on):
         v = read_metric(m["name"], record)
@@ -391,7 +450,7 @@ def run(workload: str, seed: int, seconds: float, trace_on: bool) -> dict:
     for k, c in checks.items():
         log(f"[check] {k} {c['value']} limit {c['limit']}")
     result["checks"] = checks
-    return _finite(result)
+    return _finite(result), record
 
 
 def _finite(x):
@@ -413,7 +472,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     try:
-        result = run(args.workload, args.seed, args.seconds, bool(args.trace))
+        result, _ = measure(args.workload, args.seed, args.seconds, bool(args.trace))
     except NoChip as e:
         log(f"[fail] {e}")
         return 2

@@ -46,11 +46,9 @@ def main(argv=None) -> int:
 
     found = H.resolve(args.workload)
     devs, _ = H.require_chips(found["cell"]["chips"])
-    from repro.launch.cache import enable_compile_cache
-
     from bench import trace
 
-    enable_compile_cache()
+    H.enable_cache()
     cell = H.Cell(found["config"], found["traffic"], devs)
     params, opt, pf, _ = H.first_steps(cell, args.seed)
     with H.profiled(True) as prof:

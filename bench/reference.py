@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-VARIANTS = ("fp32", "fp8", "half_batch")
+VARIANTS = ("fp32", "fp8", "half_batch")  # the reference, the control, planted faults
 ROWS_PER_BLOCK = 1  # rows of a batch whose gradient is taken at once
 
 

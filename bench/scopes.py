@@ -15,15 +15,22 @@ names:
   ``jvp(forward)`` or ``forward``; anything else is ``unscoped``;
 - per-axis collective time: the union of the collective intervals (an
   asynchronous op from its start to its done) of the collectives that
-  span each axis; one over two axes counts in both.
+  span each axis; one over two axes counts in both;
+- scope own time: each op's own time goes to every scope its ``op_name``
+  holds (``scopes_of``), so a nested scope counts inside its parent, and a
+  scope under a transformation (``jvp(moe)``, ``transpose(jvp(moe))``)
+  counts as the scope itself, forward and backward together.  A later
+  configuration's metric reads its own scope here with no edit to this
+  file.
 
-Both are means over the devices, in seconds.  The reduction keeps its own
+All are means over the devices, in seconds.  The reduction keeps its own
 reading of the HLO text, so its numbers do not move when the program's
 HLO audit (``comms/schedule_bridge``) changes.  ``load_spans`` reads the
 program's own host spans (``data.produce``) from the same trace.
 
-``bench/record_scopes.py`` runs a cell's traced window and prints these
-per step; the harness does not read them yet.
+In a ``--trace 1`` run the harness puts ``per_step_ms`` of its window into
+the record its metric readers get, as ``record["scopes"]``;
+``bench/record_scopes.py`` prints the same record.
 """
 from __future__ import annotations
 
@@ -47,6 +54,7 @@ PAIRS = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
 DONE_OF = re.compile(r"-done\([^%]*%([\w.-]+)")
 MADE = re.compile(r"(?:^|/)[a-z][\w-]*\.\d+$")  # an op_name the compiler made
 COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.-]+)\s")
+WRAPPED = re.compile(r"^[\w-]+\((.*)\)$")  # a transformation's name: jvp(forward)
 CALLEES = re.compile(
     r"(?:body|condition|to_apply|calls|true_computation|false_computation)=(%?[\w.-]+)"
     r"|branch_computations=\{([^}]*)\}")
@@ -64,6 +72,19 @@ def phase_of(op_name: str) -> str:
     if any(p in parts for p in FORWARD):
         return "forward"
     return "unscoped"
+
+
+def scopes_of(op_name: str) -> set[str]:
+    """The scopes an ``op_name`` holds: every path component but the last,
+    which names the operation, with transformations unwrapped
+    (``transpose(jvp(forward))`` is ``forward``)."""
+    out = set()
+    for path in op_name.split(";"):
+        for part in path.split("/")[:-1]:
+            while m := WRAPPED.match(part):
+                part = m.group(1)
+            out.add(part)
+    return out
 
 
 def replica_groups(line: str) -> list[list[int]] | None:
@@ -191,18 +212,22 @@ def hlo_map(hlo_text: str, mesh_shape: tuple[int, ...],
 
 
 def reduce(tr: dict, ops_map: dict[str, list]) -> dict:
-    """Phase own seconds and per-axis collective seconds in the window of
-    the trace record ``tr``, means over its devices.  Ops missing from
-    ``ops_map`` count as ``unscoped`` and span no axis."""
+    """Phase own seconds, per-axis collective seconds and scope own seconds
+    in the window of the trace record ``tr``, means over its devices.  Ops
+    missing from ``ops_map`` count as ``unscoped``, span no axis and hold
+    no scope."""
     w0, w1 = tr["window"]
     per_dev = []
     for dev in tr["devices"]:
         ops = [o for o in dev["ops"] if o[1] < w1 and o[1] + o[2] > w0]
         phases = dict.fromkeys(PHASES + ("backward", "forward", "unscoped"), 0.0)
+        by_scope = {}
         clipped = [[x, max(t, w0), min(t + d, w1) - max(t, w0)] for x, t, d in ops]
         for text, s in trace.self_times(clipped):
             op = ops_map.get(trace.op_name(text))
             phases[phase_of(op[0]) if op else "unscoped"] += s
+            for scope in scopes_of(op[0]) if op else ():
+                by_scope[scope] = by_scope.get(scope, 0.0) + s
         axes = {}
         for text, t, d in ops:
             for ax in (ops_map.get(trace.op_name(text)) or ["", []])[1]:
@@ -210,13 +235,15 @@ def reduce(tr: dict, ops_map: dict[str, list]) -> dict:
         per_dev.append((phases, {
             ax: trace._length(trace._union(trace._clip(
                 trace.collective_intervals(a), w0, w1))) * 1e-9
-            for ax, a in axes.items()}))
+            for ax, a in axes.items()}, by_scope))
     n = len(per_dev)
-    names = sorted({ax for _, a in per_dev for ax in a})
-    return {
-        "phases": {k: sum(p[k] for p, _ in per_dev) / n for k in per_dev[0][0]},
-        "axes": {ax: sum(a.get(ax, 0.0) for _, a in per_dev) / n for ax in names},
-    }
+
+    def mean(dicts):
+        names = dict.fromkeys(k for d in dicts for k in d)
+        return {k: sum(d.get(k, 0.0) for d in dicts) / n for k in names}
+
+    phases, axes, by_scope = zip(*per_dev)
+    return {"phases": mean(phases), "axes": mean(axes), "by_scope": mean(by_scope)}
 
 
 def load_spans(log_dir: str, prefix: str = "data.") -> list[list]:
@@ -246,14 +273,11 @@ def span_seconds(spans: list[list], window: list[int]) -> dict[str, float]:
 
 
 def per_step_ms(tr: dict, ops_map: dict[str, list], steps: int) -> dict:
-    """Device ms per step of each phase and of each mesh axis's collectives,
-    and host ms per step of each of the program's ``data.*`` spans, in the
-    window of the trace record ``tr`` (its ``"data"`` key holds the spans,
-    from ``load_spans``)."""
+    """Device ms per step of each phase, of each mesh axis's collectives and
+    of each scope, and host ms per step of each of the program's ``data.*``
+    spans, in the window of the trace record ``tr`` (its ``"data"`` key
+    holds the spans, from ``load_spans``)."""
     r = reduce(tr, ops_map)
-    return {
-        "phases_ms": {k: v / steps * 1e3 for k, v in r["phases"].items()},
-        "axes_ms": {k: v / steps * 1e3 for k, v in r["axes"].items()},
-        "data_ms": {k: v / steps * 1e3
-                    for k, v in span_seconds(tr.get("data", []), tr["window"]).items()},
-    }
+    r["data"] = span_seconds(tr.get("data", []), tr["window"])
+    return {f"{k}_ms": {name: v / steps * 1e3 for name, v in r[k].items()}
+            for k in ("phases", "axes", "by_scope", "data")}

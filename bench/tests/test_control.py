@@ -1,6 +1,7 @@
-"""The control, the reference computed with float8 products put in the
-program's place, fails the comparison, and the reference passes against
-itself.  Tiny size, on the CPU; the chip readings at the cells' own sizes
+"""The control of each configuration's reference module (its second
+variant; for ``bench/reference.py`` the reference computed with float8
+products) put in the program's place fails the comparison, and the
+reference passes against itself.  Tiny size, on the CPU; the chip readings at the cells' own sizes
 come from bench/calibrate.py and are in PERF.md."""
 import json
 
@@ -8,10 +9,10 @@ import pytest
 
 from bench import check
 from bench.data import Tokens
-from bench.reference import Reference
+from bench.harness import config_module
 from bench.tests.tiny import REPO, TINY, TINY_LIMITS, TINY_TRAFFIC
 
-CONFIGS = ["qwen2.5-3b", "qwen2.5-14b"]
+CONFIGS = [c["name"] for c in json.loads((REPO / "BENCHMARK.json").read_text())["configs"]]
 
 
 def _tiny(name):
@@ -20,7 +21,7 @@ def _tiny(name):
 
 
 def _readings(c, train, seed, variant):
-    ref = Reference(c, train, variant)
+    ref = config_module(c, "reference").Reference(c, train, variant)
     data = Tokens(c["vocab_size"], 4, TINY_TRAFFIC["seq"], seed)
     out = ref.run(seed, [data.batch_at(s) for s in range(3)])
     return {"losses": out["losses"],
@@ -33,10 +34,11 @@ def _readings(c, train, seed, variant):
 def test_control_fails_and_reference_agrees_with_itself(name, seed):
     c = _tiny(name)
     train = json.loads((REPO / "bench" / "traffic" / "themis.1chip.json").read_text())["train"]
-    truth = _readings(c, train, seed, "fp32")
+    sound, control, *_ = config_module(c, "reference").VARIANTS
+    truth = _readings(c, train, seed, sound)
     same = check.numbers(truth, truth)
     assert same == {"loss_gap": 0.0, "grad_gap": 0.0, "change_gap": 0.0}
-    ctrl = check.numbers(_readings(c, train, seed, "fp8"), truth)
+    ctrl = check.numbers(_readings(c, train, seed, control), truth)
     ctrl["nonfinite_losses"] = 0
     ok, checks = check.judge(ctrl, TINY_LIMITS)
     print(name, seed, ctrl)

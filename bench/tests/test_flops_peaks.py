@@ -3,7 +3,7 @@ import json
 
 import pytest
 
-from bench import flops, peaks
+from bench import flops, harness, peaks
 from bench.harness import ROOT
 
 
@@ -40,3 +40,22 @@ def test_v5e_peak_and_unknown_kind():
         peaks.peak("TPU v9 imaginary")
     with pytest.raises(KeyError):
         peaks.peak("cpu")
+
+
+# Model FLOPs per step of each cell as the benchmark counted them before the
+# configuration files named their FLOP module; mfu rests on them.
+CELL_FLOPS = {
+    "qwen2.5-3b.themis.1chip": 13638668648448,
+    "qwen2.5-14b.themis.1chip": 9414031441920,
+    "qwen2.5-3b.themis.2x2": 54554674593792,
+    "qwen2.5-3b.gspmd.1chip": 13638668648448,
+}
+
+
+@pytest.mark.parametrize("workload", sorted(CELL_FLOPS))
+def test_each_cell_counts_by_the_module_its_file_names(workload):
+    found = harness.resolve(workload)
+    c, t = found["config"], found["traffic"]
+    count = harness.config_module(c, "flops").train_step_flops(
+        c, t["batch_per_chip"] * found["cell"]["chips"], t["seq"])
+    assert count == CELL_FLOPS[workload]

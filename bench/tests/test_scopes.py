@@ -227,3 +227,94 @@ def test_recorded_scoped_trace(name, devices):
     assert set(r["axes"]) == {"data", "model"}
     assert sum(r["axes"].values()) >= 0.99 * tr["collective_s"]
     assert r["axes"]["data"] == pytest.approx(r["axes"]["model"], rel=0.1)
+
+
+@pytest.mark.parametrize("op_name,held", [
+    ("jit(step)/shard_map/themis_rs/rs_data/reduce_scatter",
+     {"step", "shard_map", "themis_rs", "rs_data"}),
+    ("jit(step)/transpose(jvp(forward))/moe/router/dot_general",
+     {"step", "forward", "moe", "router"}),
+    ("jit(step)/optimizer/mul;jit(step)/shard_map", {"step", "optimizer"}),
+    ("params", set()),
+    ("", set()),
+])
+def test_scopes_of_hand_written_op_names(op_name, held):
+    assert scopes.scopes_of(op_name) == held
+
+
+def test_by_scope_by_hand():
+    """Every op's own time goes to each scope it holds: a nested scope counts
+    inside its parent, and a scope counts forward and backward together."""
+    r = scopes.reduce(_record(OPS), scopes.hlo_map(HLO, *MESH))
+    ns = {k: round(v * 1e9) for k, v in r["by_scope"].items()}
+    assert ns == {"step": 90, "forward": 30, "themis_flatten": 30, "themis_rs": 10,
+                  "rs_data": 10, "themis_ag": 10, "ag_model": 10, "themis_unravel": 10}
+    ops_map = {"fusion.1": ["jit(step)/jvp(forward)/moe/router/dot_general", []],
+               "fusion.2": ["jit(step)/transpose(jvp(forward))/moe/dot_general", []],
+               "fusion.3": ["jit(step)/jvp(forward)/dot_general", []]}
+    ops = [("%fusion.1 = f32[8] fusion(...)", 0, 10),
+           ("%fusion.2 = f32[8] fusion(...)", 10, 30),
+           ("%fusion.3 = f32[8] fusion(...)", 40, 20)]
+    r = scopes.per_step_ms(_record(ops), ops_map, steps=2)
+    assert r["by_scope_ms"] == pytest.approx(
+        {"step": 30e-6, "forward": 30e-6, "moe": 20e-6, "router": 5e-6})
+    assert r["phases_ms"]["forward"] == pytest.approx(15e-6)
+    assert r["phases_ms"]["backward"] == pytest.approx(15e-6)
+
+
+NEW_READERS = {
+    "forward_ms": ("phases_ms", "forward"), "backward_ms": ("phases_ms", "backward"),
+    "optimizer_ms": ("phases_ms", "optimizer"),
+    "themis_flatten_ms": ("phases_ms", "themis_flatten"),
+    "themis_rs_ms": ("phases_ms", "themis_rs"), "themis_ag_ms": ("phases_ms", "themis_ag"),
+    "themis_unravel_ms": ("phases_ms", "themis_unravel"),
+    "collective_data_ms": ("axes_ms", "data"), "collective_model_ms": ("axes_ms", "model"),
+    "input_produce_ms": ("data_ms", "data.produce"),
+}
+
+
+def _recorded(name):
+    with gzip.open(DATA / f"{name}.json.gz", "rt") as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name,devices", [("trace_3b_themis_1chip_scoped", 1),
+                                          ("trace_3b_themis_2x2_scoped", 4)])
+def test_the_record_of_a_recorded_trace_and_its_readers(name, devices):
+    """What the harness adds to a traced run's record: the phases and the
+    unscoped rest add up to the busy time within 0.1%, ``by_scope`` holds
+    each phase's scope at least at the phase's time, and each new reader
+    returns its number where the cell has it and nothing where it does not
+    (the all-gather phase on one chip, in the step these traces were
+    recorded from; the mesh axes on one chip)."""
+    rec = _recorded(name)
+    steps = 3
+    record = {"steps": steps, **harness.trace_readings(rec, rec["hlo"], steps)}
+    sc = record["scopes"]
+    busy_ms = record["trace"]["busy_s"] / steps * 1e3
+    assert sum(sc["phases_ms"].values()) == pytest.approx(busy_ms, rel=1e-3)
+    for phase in scopes.PHASES:
+        assert sc["by_scope_ms"].get(phase, 0.0) >= sc["phases_ms"][phase] * (1 - 1e-9)
+    assert sc["by_scope_ms"]["forward"] == pytest.approx(
+        sc["phases_ms"]["forward"] + sc["phases_ms"]["backward"], rel=1e-3)
+    absent = {"themis_ag_ms", "collective_data_ms", "collective_model_ms"} \
+        if devices == 1 else set()
+    for metric, (kind, key) in NEW_READERS.items():
+        got = harness.read_metric(metric, record)
+        if metric in absent:
+            assert got is None, metric
+        else:
+            assert got == pytest.approx(sc[kind][key]) and got > 0, metric
+
+
+def test_readers_of_a_step_without_scopes_and_of_an_untraced_run_read_nothing():
+    """A step whose op names hold no phase (a program before the scopes, or a
+    gspmd step for the Themis phases) gives no phase reading, and an
+    untraced run, whose record has no scopes, none at all."""
+    rec = _recorded("trace_3b_themis_1chip_scoped")
+    unnamed = {k: ["", axes] for k, (_, axes) in rec["hlo"].items()}
+    record = {"steps": 1, **harness.trace_readings(rec, unnamed, 1)}
+    for metric, (kind, _) in NEW_READERS.items():
+        got = harness.read_metric(metric, record)
+        assert (got is None) == (kind != "data_ms"), metric
+        assert harness.read_metric(metric, {"steps": 1, "trace": None, "scopes": None}) is None

@@ -21,7 +21,6 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from bench import harness as H
-from bench.reference import ROWS_PER_BLOCK, Reference, init_params
 
 HBM = 15.75 * 2**30  # what the compiler lets a program use of a v5e chip
 CELLS = [w["name"] for w in json.loads((H.ROOT / "BENCHMARK.json").read_text())["workloads"]]
@@ -77,10 +76,11 @@ def test_reference_fits_one_chip(topo, workload):
     found = H.resolve(workload)
     config, traffic = found["config"], found["traffic"]
     one = SingleDeviceSharding(topo.devices[0])
-    ref = Reference(config, traffic["train"])
+    reference = H.config_module(config, "reference")
+    ref = reference.Reference(config, traffic["train"])
     p = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
-                     jax.eval_shape(lambda: init_params(0, config)))
-    rows = jax.ShapeDtypeStruct((ROWS_PER_BLOCK, traffic["seq"]),
+                     jax.eval_shape(lambda: reference.init_params(0, config)))
+    rows = jax.ShapeDtypeStruct((reference.ROWS_PER_BLOCK, traffic["seq"]),
                                 jnp.int32, sharding=one)
     scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=one)
     grad = ref._acc_grad.lower(p, p, rows, rows, scalar).compile().memory_analysis()

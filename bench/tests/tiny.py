@@ -3,7 +3,8 @@ run on the CPU in a child process.
 
 ``tiny_copy`` copies ``BENCHMARK.json`` and ``bench/`` into a directory and
 cuts every configuration to a few small layers and every traffic mix to a
-small batch; widths and names stay as the cells name them.  ``run_cell``
+small batch; names stay as the cells name them, and a head dimension that
+the file expects of the program follows the tiny widths.  ``run_cell``
 runs one cell of such a copy through ``bench.harness.main`` in a child
 process on the CPU, with as many virtual devices as the cell asks for.
 The child skips the harness's look for a chip (it reports the CPU as the
@@ -82,6 +83,9 @@ def tiny_copy(dest: Path, limits: dict | None = None) -> Path:
         c = json.loads(path.read_text())
         c.update(TINY)
         c["program"]["replace"].update({PROGRAM_KEYS[k]: v for k, v in TINY.items()})
+        if "resolved_head_dim" in c["program"]["expect"]:
+            c["program"]["expect"]["resolved_head_dim"] = (
+                TINY["hidden_size"] // TINY["num_attention_heads"])
         path.write_text(json.dumps(c))
     for path in (dest / "bench" / "traffic").glob("*.json"):
         t = json.loads(path.read_text())
